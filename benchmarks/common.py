@@ -25,6 +25,29 @@ TINY = os.environ.get("REPRO_BENCH_TINY", "") not in ("", "0")
 #: Where BENCH_*.json files land (created on demand).
 OUT_DIR = os.environ.get("REPRO_BENCH_OUT", "bench_out")
 
+#: Persistent compile cache of the entry-point scripts when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed path inside the checkout
+#: (git-ignored), because the path is part of the cache key.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured as JAX reads it and
+    nothing else is configured; otherwise the cache goes to
+    ``COMPILE_CACHE_DIR``.  Returns the directory in use.  Call it before
+    the first compile; library modules and tests never call it."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+
+        path = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 SIZES = {
     "small": VM_SPEC.make(vcpus=1, ram_mb=2000, disk_gb=20),
     "medium": VM_SPEC.make(vcpus=2, ram_mb=4000, disk_gb=40),

@@ -14,6 +14,9 @@ def main() -> None:
         bench_sim_utilization,
         bench_tables,
     )
+    from .common import use_compile_cache
+
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     bench_tables.run()            # paper Tables 3-6 (correctness + latency)

@@ -488,7 +488,6 @@ def _sharded_screen(
     decision.)  On non-TPU backends the kernel runs in interpret mode
     (parity-gated by tests/test_sharded_parity.py).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -597,12 +596,12 @@ def _sharded_screen(
         # The per-request exclusion id is a replicated scalar (like req_*).
         operands += (exclude_zone,)
         in_specs += (rep,)
-    return shard_map(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(rep, rep, rep),
-        check_rep=False,
+        check_vma=False,
     )(*operands)
 
 

@@ -153,16 +153,16 @@ def _tile_stage1(
     if zone_ref is not None and excl_ref is not None:
         excl = excl_ref[0, 0]
         fits &= (excl < 0) | (zone_ref[...][0] != excl)
+    # The gates below are boolean algebra, not ``jnp.where``: Mosaic cannot
+    # lower a select whose value operands are both boolean vectors.
     if churn_threshold is not None and churn_ref is not None:
-        fits &= jnp.where(
-            pre, churn_ref[...][0] <= jnp.float32(churn_threshold), True
-        )
+        fits &= ~pre | (churn_ref[...][0] <= jnp.float32(churn_threshold))
     if require_free_slot:
         has_free = jnp.min(validf, axis=0) < 0.5
-        fits &= jnp.where(pre, has_free, True)
+        fits &= ~pre | has_free
     cost_lb = jnp.where(pre, 0.0, cost_lb)
     cost_ub = jnp.where(pre, 0.0, cost_ub)
-    feasible = jnp.where(pre, fits, feasible)
+    feasible = (pre & fits) | (~pre & feasible)
     valid = fits & feasible
 
     over_raw = jnp.where(overcommitted, -1.0, 0.0)

@@ -466,3 +466,21 @@ def test_sharded_simulator_smoke():
             {k: v for k, v in summary.items() if "latency" not in k}
         )
     assert runs[0] == runs[1]
+
+
+@multi_device
+def test_chip_smoke_sharded_phase_tiny():
+    """chip_smoke.py's ``--chips 4`` phase at a small fleet: the sharded
+    fleet's decisions and arrays equal the unsharded fleet's bitwise, and
+    the phase's own check finds the mesh screen's all-gather."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    out = smoke.sharded_phase(
+        n_hosts=512, n_requests=48, seed=1, mesh=fleet_mesh(4)
+    )
+    assert out["shards"] == 4 and out["placed"] > 0
