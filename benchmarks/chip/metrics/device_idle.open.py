@@ -1,0 +1,2 @@
+"""Per-layer metric device_idle.open (see readers.device_idle)."""
+from readers import device_idle as read  # noqa: F401

@@ -1,0 +1,2 @@
+"""Per-layer metric frontend_host_ms.open (see readers.frontend_host_ms)."""
+from readers import frontend_host_ms as read  # noqa: F401

@@ -1,0 +1,2 @@
+"""Per-layer metric screen_roofline.backlog (see readers.screen_roofline)."""
+from readers import screen_roofline as read  # noqa: F401
