@@ -1,0 +1,2 @@
+"""Per-layer metric drain_fill.open (see program_trace.drain_fill)."""
+from program_trace import drain_fill as read  # noqa: F401

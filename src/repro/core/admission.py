@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import obs
 from .jax_scheduler import SoAFleetState, _step_core
 from .policy import COST_KIND_IDS, SchedulerPolicy
 from .screen_math import churn_stats
@@ -296,25 +297,27 @@ def _drain_entry(
         qs, slot, ok = queue_push(qs, *xs)
         return qs, (slot, ok)
 
-    q, (new_slot, pushed) = jax.lax.scan(
-        push_body, q,
-        (new_res, new_pre, new_dom, new_kind, new_period, new_excl, new_cls,
-         new_t, new_price, new_live),
-    )
+    with jax.named_scope("queue_push"):
+        q, (new_slot, pushed) = jax.lax.scan(
+            push_body, q,
+            (new_res, new_pre, new_dom, new_kind, new_period, new_excl,
+             new_cls, new_t, new_price, new_live),
+        )
 
-    idx, take = queue_select(
-        q, policy.admit_batch, now=now, aging_rate=policy.aging_rate,
-        n_classes=policy.n_classes,
-    )
-    b = idx.shape[0]
-    b_res = jnp.where(take[:, None], q.res[idx], PAD_RES)
-    b_pre = jnp.where(take, q.preemptible[idx], False)
-    b_dom = jnp.where(take, q.domain[idx], -1)
-    b_kind = jnp.where(take, q.cost_kind[idx], -1)
-    b_period = jnp.where(take, q.period[idx], -1.0)
-    b_excl = jnp.where(take, q.exclude_zone[idx], -1)
-    b_price = jnp.where(take, q.price[idx], 1.0)
-    b_now = jnp.full((b,), now, jnp.float32)
+    with jax.named_scope("queue_select"):
+        idx, take = queue_select(
+            q, policy.admit_batch, now=now, aging_rate=policy.aging_rate,
+            n_classes=policy.n_classes,
+        )
+        b = idx.shape[0]
+        b_res = jnp.where(take[:, None], q.res[idx], PAD_RES)
+        b_pre = jnp.where(take, q.preemptible[idx], False)
+        b_dom = jnp.where(take, q.domain[idx], -1)
+        b_kind = jnp.where(take, q.cost_kind[idx], -1)
+        b_period = jnp.where(take, q.period[idx], -1.0)
+        b_excl = jnp.where(take, q.exclude_zone[idx], -1)
+        b_price = jnp.where(take, q.price[idx], 1.0)
+        b_now = jnp.full((b,), now, jnp.float32)
 
     if policy.storm_threshold is not None:
         # fleet-wide rate = last entry of the shared fused churn reduction
@@ -336,13 +339,18 @@ def _drain_entry(
             req_exclude=excl if policy.relocation_on else None,
         )
 
-    fleet_state, (host_idx, slot, ok, kill, fell_back, margin) = jax.lax.scan(
-        body, fleet_state,
-        (b_res, b_pre, b_dom, b_now, b_price, b_kind, b_period, excl_xs),
-    )
-    placed = ok & take
-    wait = jnp.where(placed, now - q.enq_t[idx], 0.0)
-    q, dropped = queue_pop(q, idx, take, placed, policy.max_retries)
+    with jax.named_scope("decide"):
+        fleet_state, (host_idx, slot, ok, kill, fell_back, margin) = (
+            jax.lax.scan(
+                body, fleet_state,
+                (b_res, b_pre, b_dom, b_now, b_price, b_kind, b_period,
+                 excl_xs),
+            )
+        )
+    with jax.named_scope("queue_pop"):
+        placed = ok & take
+        wait = jnp.where(placed, now - q.enq_t[idx], 0.0)
+        q, dropped = queue_pop(q, idx, take, placed, policy.max_retries)
     return fleet_state, q, (
         new_slot, pushed, idx, take, placed, host_idx, slot, kill,
         fell_back, margin, wait, dropped, degraded, q.depth,
@@ -370,7 +378,13 @@ class AdmissionStats:
     Conservation invariant (pinned by tests/test_admission.py): every
     arrival is in exactly one bucket —
     ``arrivals == admitted + rejected_overflow + rejected_retry
-    + queue_depth + pending``.
+    + queue_depth + pending``; and every attempt is exactly one outcome —
+    ``attempts == admitted + retries + rejected_retry``.
+
+    The per-request wall samples (``queue_wall_s`` .. ``refused``) hold one
+    entry per request decided by placement or by refusal after retries, in
+    the order decided.  Their three segments add up to the request's
+    submit → outputs-fetched time (its ``wall_wait_s`` entry when placed).
     """
 
     arrivals: int = 0
@@ -382,14 +396,39 @@ class AdmissionStats:
     #: preemptible attempts demoted to non-preemptible by storm degradation
     degraded: int = 0
     queue_depth: int = 0
+    #: drain rows that held a request (admitted, retried or refused)
+    attempts: int = 0
+    #: attempts whose decision ran the full-enumeration fallback
+    fallbacks: int = 0
     #: sim-time admission latency (drain time - arrival time) per placement
     wait_s: List[float] = dataclasses.field(default_factory=list)
     #: wall-clock submit → outcome-absorbed latency per placement (seconds)
     wall_wait_s: List[float] = dataclasses.field(default_factory=list)
+    #: submit → dispatch of the first drain that attempted the request
+    queue_wall_s: List[float] = dataclasses.field(default_factory=list)
+    #: that first attempt's dispatch → dispatch of the deciding drain
+    retry_wall_s: List[float] = dataclasses.field(default_factory=list)
+    #: deciding drain's dispatch → its outputs fetched to the host
+    fetch_wall_s: List[float] = dataclasses.field(default_factory=list)
+    #: attempts the request took (1 .. ``max_retries``)
+    tries: List[int] = dataclasses.field(default_factory=list)
+    #: True where the request was refused after retries, False where placed
+    refused: List[bool] = dataclasses.field(default_factory=list)
 
     @property
     def rejected(self) -> int:
         return self.rejected_overflow + self.rejected_retry
+
+    def _decided(self, w: "_Waiting", dispatch: float, fetched: float,
+                 refused: bool) -> None:
+        """Record the wall samples of request ``w``, decided by the drain
+        dispatched at ``dispatch`` whose outputs were fetched at ``fetched``
+        (``time.perf_counter()`` stamps)."""
+        self.queue_wall_s.append(w.first_dispatch - w.submit_wall)
+        self.retry_wall_s.append(dispatch - w.first_dispatch)
+        self.fetch_wall_s.append(fetched - dispatch)
+        self.tries.append(w.tries)
+        self.refused.append(refused)
 
     @staticmethod
     def _pct(samples: Sequence[float], pct: float) -> float:
@@ -422,6 +461,8 @@ class AdmissionStats:
             "retries": self.retries,
             "degraded": self.degraded,
             "queue_depth": self.queue_depth,
+            "attempts": self.attempts,
+            "fallbacks": self.fallbacks,
             "wait_p50_s": self._pct(self.wait_s, 50),
             "wait_p99_s": self._pct(self.wait_s, 99),
             "wall_p50_us": self._pct(self.wall_wait_s, 50) * 1e6,
@@ -456,6 +497,9 @@ class _Waiting:
     klass: int
     enq_t: float
     submit_wall: float  # time.perf_counter() at submit
+    #: time.perf_counter() at the dispatch of the first drain attempting it
+    first_dispatch: Optional[float] = None
+    tries: int = 0      # drains that attempted it so far
 
 
 class AdmissionFrontEnd:
@@ -513,14 +557,16 @@ class AdmissionFrontEnd:
 
     def submit(self, req: Request, now: float, price: float = 1.0) -> None:
         """Accept one arrival into the accumulation buffer (never blocks)."""
-        self.fleet._req_arrays(req)  # validate cost kind early, like direct paths
-        self._pending.append(
-            _Waiting(
-                request=req, price=float(price), klass=self._klass_of(req),
-                enq_t=float(now), submit_wall=time.perf_counter(),
+        with obs.span(obs.SUBMIT):
+            self.fleet._req_arrays(req)  # validate cost kind early
+            self._pending.append(
+                _Waiting(
+                    request=req, price=float(price),
+                    klass=self._klass_of(req), enq_t=float(now),
+                    submit_wall=time.perf_counter(),
+                )
             )
-        )
-        self.stats.arrivals += 1
+            self.stats.arrivals += 1
 
     def submit_relocation(
         self, req: Request, victim_id: str, zone: str, now: float,
@@ -577,33 +623,39 @@ class AdmissionFrontEnd:
                 retried=(), queue_depth=0,
             ) if block else None
 
-        a = max(4, 1 << (len(pend) - 1).bit_length()) if pend else 4
-        d = len(self.fleet.spec.dims)
-        res = np.full((a, d), PAD_RES, np.float32)
-        pre = np.zeros((a,), bool)
-        dom = np.full((a,), -1, np.int32)
-        kind = np.full((a,), -1, np.int32)
-        per = np.full((a,), -1.0, np.float32)
-        exc = np.full((a,), -1, np.int32)
-        cls = np.zeros((a,), np.int32)
-        enq = np.zeros((a,), np.float32)
-        price = np.ones((a,), np.float32)
-        live = np.zeros((a,), bool)
-        for i, w in enumerate(pend):
-            r, p, dm, kd, pd, ex = self.fleet._req_arrays(w.request)
-            res[i], pre[i], dom[i], kind[i], per[i], exc[i] = (
-                r, p, dm, kd, pd, ex
-            )
-            cls[i], enq[i], price[i], live[i] = w.klass, w.enq_t, w.price, True
+        with obs.span(obs.PACK):
+            a = max(4, 1 << (len(pend) - 1).bit_length()) if pend else 4
+            d = len(self.fleet.spec.dims)
+            res = np.full((a, d), PAD_RES, np.float32)
+            pre = np.zeros((a,), bool)
+            dom = np.full((a,), -1, np.int32)
+            kind = np.full((a,), -1, np.int32)
+            per = np.full((a,), -1.0, np.float32)
+            exc = np.full((a,), -1, np.int32)
+            cls = np.zeros((a,), np.int32)
+            enq = np.zeros((a,), np.float32)
+            price = np.ones((a,), np.float32)
+            live = np.zeros((a,), bool)
+            for i, w in enumerate(pend):
+                r, p, dm, kd, pd, ex = self.fleet._req_arrays(w.request)
+                res[i], pre[i], dom[i], kind[i], per[i], exc[i] = (
+                    r, p, dm, kd, pd, ex
+                )
+                cls[i], enq[i], price[i], live[i] = (
+                    w.klass, w.enq_t, w.price, True
+                )
 
-        policy = self.fleet._flush_policy()
-        fn = _drain_donated if policy.donate else _drain_kept
-        self.fleet.state, self.qstate, aux = fn(
-            self.fleet.state, self.qstate,
-            res, pre, dom, kind, per, exc, cls, enq, price, live,
-            jnp.asarray(now, jnp.float32), policy=policy,
-        )
-        self._inflight = (pend, float(now), aux)
+        seq = self.stats.drains
+        with obs.span(obs.DISPATCH, drain=seq):
+            dispatched = time.perf_counter()
+            policy = self.fleet._flush_policy()
+            fn = _drain_donated if policy.donate else _drain_kept
+            self.fleet.state, self.qstate, aux = fn(
+                self.fleet.state, self.qstate,
+                res, pre, dom, kind, per, exc, cls, enq, price, live,
+                jnp.asarray(now, jnp.float32), policy=policy,
+            )
+        self._inflight = (pend, float(now), aux, seq, dispatched)
         self.stats.drains += 1
         return self.flush() if block else None
 
@@ -611,81 +663,90 @@ class AdmissionFrontEnd:
         """Absorb the in-flight drain's outcomes (blocks on the device)."""
         if self._inflight is None:
             return None
-        pend, now, aux = self._inflight
+        pend, now, aux, seq, dispatched = self._inflight
         self._inflight = None
-        (new_slot, pushed, idx, take, placed, host_idx, slot, kill,
-         fell_back, margin, wait, dropped, degraded, depth) = (
-            np.asarray(x) for x in aux
-        )
+        with obs.span(obs.FETCH, drain=seq):
+            (new_slot, pushed, idx, take, placed, host_idx, slot, kill,
+             fell_back, margin, wait, dropped, degraded, depth) = (
+                np.asarray(x) for x in aux
+            )
         wall_now = time.perf_counter()
-
-        rejected: List[Request] = []
-        # 1. arrivals → queue rows (or instant overflow rejection)
-        for i, w in enumerate(pend):
-            if pushed[i]:
-                self.slots[int(new_slot[i])] = w
-            else:
-                self.stats.rejected_overflow += 1
-                rejected.append(w.request)
-                reloc = self._reloc.pop(w.request.id, None)
-                if reloc is not None:  # overflow: victim keeps running
-                    self.fleet._settle_relocation_rejected(
-                        reloc[0], reloc[1], now
+        with obs.span(obs.MIRROR):
+            rejected: List[Request] = []
+            # 1. arrivals → queue rows (or instant overflow rejection)
+            for i, w in enumerate(pend):
+                if pushed[i]:
+                    self.slots[int(new_slot[i])] = w
+                else:
+                    self.stats.rejected_overflow += 1
+                    rejected.append(w.request)
+                    reloc = self._reloc.pop(w.request.id, None)
+                    if reloc is not None:  # overflow: victim keeps running
+                        self.fleet._settle_relocation_rejected(
+                            reloc[0], reloc[1], now
+                        )
+            # 2. attempted rows, in service order
+            outcomes, retried, attempts = [], [], []
+            for j in range(len(idx)):
+                if not take[j]:
+                    continue
+                row = int(idx[j])
+                w = self.slots[row]
+                assert w is not None, "drained an empty queue row"
+                if w.first_dispatch is None:
+                    w.first_dispatch = dispatched
+                w.tries += 1
+                # Storm degradation demoted this attempt on device; mirror
+                # the demotion so the python bookkeeping matches what ran.
+                req = w.request
+                if degraded[j]:
+                    req = dataclasses.replace(req, preemptible=False)
+                    self.stats.degraded += 1
+                attempts.append((req, bool(placed[j])))
+                if placed[j]:
+                    self.slots[row] = None
+                    out = self.fleet._absorb(
+                        req, now, w.price, int(host_idx[j]), int(slot[j]),
+                        True, kill[j],
                     )
-        # 2. attempted rows, in service order
-        outcomes, retried, attempts = [], [], []
-        for j in range(len(idx)):
-            if not take[j]:
-                continue
-            row = int(idx[j])
-            w = self.slots[row]
-            assert w is not None, "drained an empty queue row"
-            # Storm degradation demoted this attempt on device; mirror the
-            # demotion so the python bookkeeping matches what actually ran.
-            req = w.request
-            if degraded[j]:
-                req = dataclasses.replace(req, preemptible=False)
-                self.stats.degraded += 1
-            attempts.append((req, bool(placed[j])))
-            if placed[j]:
-                self.slots[row] = None
-                out = self.fleet._absorb(
-                    req, now, w.price, int(host_idx[j]), int(slot[j]),
-                    True, kill[j],
-                )
-                outcomes.append(out)
-                self.stats.admitted += 1
-                self.stats.wait_s.append(float(wait[j]))
-                self.stats.wall_wait_s.append(wall_now - w.submit_wall)
-                reloc = self._reloc.pop(req.id, None)
-                if reloc is not None:  # make-before-break: replacement is
-                    # live — NOW the victim may die.
-                    self.fleet._settle_relocation_placed(
-                        reloc[0], reloc[1], out, now
-                    )
-            elif dropped[j]:
-                self.slots[row] = None
-                self.stats.rejected_retry += 1
-                rejected.append(w.request)
-                reloc = self._reloc.pop(req.id, None)
-                if reloc is not None:  # victim keeps running; zone backs off
-                    self.fleet._settle_relocation_rejected(
-                        reloc[0], reloc[1], now
-                    )
-            else:
-                self.stats.retries += 1
-                retried.append(w.request)
-        n_take = int(take.sum())
-        if n_take:
-            fb = fell_back[take]
-            mg = margin[take]
-            self.fleet._observe(int(fb.sum()), float(mg.min()), n_take)
-        self.stats.queue_depth = int(depth)
-        return DrainResult(
-            now=now, attempts=tuple(attempts), outcomes=tuple(outcomes),
-            rejected=tuple(rejected), retried=tuple(retried),
-            queue_depth=int(depth),
-        )
+                    outcomes.append(out)
+                    self.stats.admitted += 1
+                    self.stats.wait_s.append(float(wait[j]))
+                    self.stats.wall_wait_s.append(wall_now - w.submit_wall)
+                    self.stats._decided(w, dispatched, wall_now, False)
+                    reloc = self._reloc.pop(req.id, None)
+                    if reloc is not None:  # make-before-break: the
+                        # replacement is live — NOW the victim may die.
+                        self.fleet._settle_relocation_placed(
+                            reloc[0], reloc[1], out, now
+                        )
+                elif dropped[j]:
+                    self.slots[row] = None
+                    self.stats.rejected_retry += 1
+                    self.stats._decided(w, dispatched, wall_now, True)
+                    rejected.append(w.request)
+                    reloc = self._reloc.pop(req.id, None)
+                    if reloc is not None:  # victim keeps running; the
+                        # zone backs off
+                        self.fleet._settle_relocation_rejected(
+                            reloc[0], reloc[1], now
+                        )
+                else:
+                    self.stats.retries += 1
+                    retried.append(w.request)
+            n_take = int(take.sum())
+            if n_take:
+                fb = fell_back[take]
+                mg = margin[take]
+                self.fleet._observe(int(fb.sum()), float(mg.min()), n_take)
+                self.stats.attempts += n_take
+                self.stats.fallbacks += int(fb.sum())
+            self.stats.queue_depth = int(depth)
+            return DrainResult(
+                now=now, attempts=tuple(attempts), outcomes=tuple(outcomes),
+                rejected=tuple(rejected), retried=tuple(retried),
+                queue_depth=int(depth),
+            )
 
     def sync(self) -> None:
         """Absorb any in-flight drain, banking its result for

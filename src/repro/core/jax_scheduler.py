@@ -777,141 +777,153 @@ def _decision_core(
     # ---- stage 1: O(N·K) screen → top-M candidates + (u, j_u) witness -------
     # omega_ub ≥ omega at float level: cost_lb ≤ best_cost and every op in
     # omega_of is monotone (shared constants, shared add order).
-    if use_mesh:
-        # Per-shard screen under shard_map; the merge reduces the gathered
-        # per-shard (top-M + witness) pairs into the global shortlist with
-        # lax.top_k's exact tie ordering, and the pmin/pmax-merged constants
-        # are bitwise equal to the fleet-wide folds.  fused_screen=True runs
-        # the per-shard screen through the Pallas kernel (no longer mutually
-        # exclusive with the mesh).
-        from .fleet_sharding import merge_shortlists
+    with jax.named_scope("stage1"):
+        if use_mesh:
+            # Per-shard screen under shard_map; the merge reduces the
+            # gathered per-shard (top-M + witness) pairs into the global
+            # shortlist with lax.top_k's exact tie ordering, and the
+            # pmin/pmax-merged constants are bitwise equal to the fleet-wide
+            # folds.  fused_screen=True runs the per-shard screen through the
+            # Pallas kernel (no longer mutually exclusive with the mesh).
+            from .fleet_sharding import merge_shortlists
 
-        all_s, all_i, consts_arr = _sharded_screen(
-            mesh,
-            free_f, free_n, schedulable, domain, slow,
-            inst_res, inst_cost, inst_valid,
-            req_res, req_preemptible, req_domain,
-            mult, require_free_slot, m_cand,
-            use_fused=bool(fused_screen),
-            churn=churn, churn_threshold=thr,
-            host_zone=host_zone, exclude_zone=exclude_zone,
-        )
-        consts = ScreenConsts.unpack(consts_arr)
-        cand, u, j_u = merge_shortlists(all_s, all_i, m_cand)
-        # Per-candidate base/valid recomputed on the gathered (replicated)
-        # shortlist rows — elementwise identical to the fleet-wide values.
-        valid_c, _, _, raw_c = stage1_of(
-            free_f[cand], free_n[cand], schedulable[cand], domain[cand],
-            slow[cand], inst_res[cand], inst_cost[cand], inst_valid[cand],
-            churn[cand] if churn_on else None,
-            host_zone[cand] if zone_on else None,
-        )
-        base_c = _base_of(mult, raw_c, consts, gates=gates)
-    elif fused_screen:
-        # One fused pass over the fleet; only the (M+1,) shortlist and the 10
-        # normalization scalars come back.  Entry M is the best omega_ub
-        # outside the shortlist with lax.top_k tie ordering — the (u, j_u)
-        # admissibility witness.
-        from repro.kernels.sched_screen import sched_screen
+            all_s, all_i, consts_arr = _sharded_screen(
+                mesh,
+                free_f, free_n, schedulable, domain, slow,
+                inst_res, inst_cost, inst_valid,
+                req_res, req_preemptible, req_domain,
+                mult, require_free_slot, m_cand,
+                use_fused=bool(fused_screen),
+                churn=churn, churn_threshold=thr,
+                host_zone=host_zone, exclude_zone=exclude_zone,
+            )
+            consts = ScreenConsts.unpack(consts_arr)
+            cand, u, j_u = merge_shortlists(all_s, all_i, m_cand)
+            # Per-candidate base/valid recomputed on the gathered
+            # (replicated) shortlist rows — elementwise identical to the
+            # fleet-wide values.
+            valid_c, _, _, raw_c = stage1_of(
+                free_f[cand], free_n[cand], schedulable[cand], domain[cand],
+                slow[cand], inst_res[cand], inst_cost[cand], inst_valid[cand],
+                churn[cand] if churn_on else None,
+                host_zone[cand] if zone_on else None,
+            )
+            base_c = _base_of(mult, raw_c, consts, gates=gates)
+        elif fused_screen:
+            # One fused pass over the fleet; only the (M+1,) shortlist and
+            # the 10 normalization scalars come back.  Entry M is the best
+            # omega_ub outside the shortlist with lax.top_k tie ordering —
+            # the (u, j_u) admissibility witness.
+            from repro.kernels.sched_screen import sched_screen
 
-        top_s, top_i, consts_arr = sched_screen(
-            free_f, free_n, schedulable, domain, slow,
-            inst_res, inst_cost, inst_valid,
-            req_res, req_preemptible, req_domain,
-            weigher_multipliers=mult,
-            require_free_slot=require_free_slot,
-            m_keep=m_cand + 1,
-            churn=churn,
-            churn_threshold=thr,
-            host_zone=host_zone,
-            exclude_zone=exclude_zone,
-        )
-        consts = ScreenConsts.unpack(consts_arr)
-        cand = top_i[:m_cand]
-        u, j_u = top_s[m_cand], top_i[m_cand]
-        # Per-candidate base/valid recomputed on the gathered rows from the
-        # kernel's constants — elementwise identical to the fleet-wide jnp
-        # values (min/max folds are reassociation-free).
-        valid_c, _, _, raw_c = stage1_of(
-            free_f[cand], free_n[cand], schedulable[cand], domain[cand],
-            slow[cand], inst_res[cand], inst_cost[cand], inst_valid[cand],
-            churn[cand] if churn_on else None,
-            host_zone[cand] if zone_on else None,
-        )
-        base_c = _base_of(mult, raw_c, consts, gates=gates)
-    else:
-        valid, cost_lb, cost_ub, raw = stage1_of(
-            free_f, free_n, schedulable, domain, slow,
-            inst_res, inst_cost, inst_valid, churn, host_zone,
-        )
-        consts = consts_of(gates, valid, cost_lb, cost_ub, *raw)
-        base = _base_of(mult, raw, consts, gates=gates)
-        ispan_ub = inv_span(consts.c_lo, consts.c_hi)
-        # Bound side chosen by the STATIC sign: ensemble lanes must keep the
-        # policy's sign so omega_ub stays an upper bound (validated by
-        # scan_sim.simulate_ensemble before any lane runs).
-        opt_cost = cost_lb if m_term_gate >= 0 else cost_ub
-        omega_ub = omega_of(opt_cost, base, valid, consts, ispan_ub, m_term,
-                            gate=m_term_gate)
-        # NOTE: top_k(M) + a masked argmax for the (u, j_u) witness, NOT the
-        # seemingly cleaner top_k(M+1) whose entry M is the same witness:
-        # XLA CPU only rewrites top_k into its fast TopK custom-call for
-        # k ≤ 64, so with the default M=64 the +1 falls off a cliff into a
-        # full stable sort of all N hosts (~22 ms at N=65536 — measured).
-        _, cand = jax.lax.top_k(omega_ub, m_cand)                # ties → low idx
-        in_short = jnp.zeros((n_hosts,), bool).at[cand].set(True)
-        out_ub = jnp.where(in_short, NEG_INF, omega_ub)
-        u = jnp.max(out_ub)
-        j_u = jnp.argmax(out_ub).astype(jnp.int32)
-        valid_c, base_c = valid[cand], base[cand]
+            top_s, top_i, consts_arr = sched_screen(
+                free_f, free_n, schedulable, domain, slow,
+                inst_res, inst_cost, inst_valid,
+                req_res, req_preemptible, req_domain,
+                weigher_multipliers=mult,
+                require_free_slot=require_free_slot,
+                m_keep=m_cand + 1,
+                churn=churn,
+                churn_threshold=thr,
+                host_zone=host_zone,
+                exclude_zone=exclude_zone,
+            )
+            consts = ScreenConsts.unpack(consts_arr)
+            cand = top_i[:m_cand]
+            u, j_u = top_s[m_cand], top_i[m_cand]
+            # Per-candidate base/valid recomputed on the gathered rows from
+            # the kernel's constants — elementwise identical to the
+            # fleet-wide jnp values (min/max folds are reassociation-free).
+            valid_c, _, _, raw_c = stage1_of(
+                free_f[cand], free_n[cand], schedulable[cand], domain[cand],
+                slow[cand], inst_res[cand], inst_cost[cand], inst_valid[cand],
+                churn[cand] if churn_on else None,
+                host_zone[cand] if zone_on else None,
+            )
+            base_c = _base_of(mult, raw_c, consts, gates=gates)
+        else:
+            valid, cost_lb, cost_ub, raw = stage1_of(
+                free_f, free_n, schedulable, domain, slow,
+                inst_res, inst_cost, inst_valid, churn, host_zone,
+            )
+            consts = consts_of(gates, valid, cost_lb, cost_ub, *raw)
+            base = _base_of(mult, raw, consts, gates=gates)
+            ispan_ub = inv_span(consts.c_lo, consts.c_hi)
+            # Bound side chosen by the STATIC sign: ensemble lanes must keep
+            # the policy's sign so omega_ub stays an upper bound (validated
+            # by scan_sim.simulate_ensemble before any lane runs).
+            opt_cost = cost_lb if m_term_gate >= 0 else cost_ub
+            omega_ub = omega_of(opt_cost, base, valid, consts, ispan_ub,
+                                m_term, gate=m_term_gate)
+            # NOTE: top_k(M) + a masked argmax for the (u, j_u) witness, NOT
+            # the seemingly cleaner top_k(M+1) whose entry M is the same
+            # witness: XLA CPU only rewrites top_k into its fast TopK
+            # custom-call for k ≤ 64, so with the default M=64 the +1 falls
+            # off a cliff into a full stable sort of all N hosts (~22 ms at
+            # N=65536 — measured).
+            _, cand = jax.lax.top_k(omega_ub, m_cand)        # ties → low idx
+            in_short = jnp.zeros((n_hosts,), bool).at[cand].set(True)
+            out_ub = jnp.where(in_short, NEG_INF, omega_ub)
+            u = jnp.max(out_ub)
+            j_u = jnp.argmax(out_ub).astype(jnp.int32)
+            valid_c, base_c = valid[cand], base[cand]
 
     # ---- stage 2: exact enumeration on the gathered shortlist ---------------
-    ispan = inv_span(consts.c_lo, consts.c_hi)
-    bc_s, bm_s, _ = _plan_terms(use_pallas, gathered=True)(
-        free_f[cand], inst_res[cand], inst_cost[cand], inst_valid[cand],
-        req_res, masks,
-    )
-    bc_s = jnp.where(req_preemptible, 0.0, bc_s)
-    bm_s = jnp.where(req_preemptible, 0, bm_s)
-    omega_s = omega_of(bc_s, base_c, valid_c, consts, ispan, m_term,
-                       gate=m_term_gate)  # (M,)
-    best_val = jnp.max(omega_s)
-    # Winner = lowest ORIGINAL index among exact-score ties (what the full
-    # path's argmax-first-hit does over the whole fleet).
-    tie_idx = jnp.where(omega_s == best_val, cand, n_hosts)
-    winner_pos = jnp.argmin(tie_idx).astype(jnp.int32)
-    w_star = tie_idx[winner_pos].astype(jnp.int32)
-    ok_s = best_val > NEG_INF / 2
-
-    # ---- admissibility: can any non-shortlisted host still win? -------------
-    # An outside host beats w* only with omega > best_val, or omega == best_val
-    # and a lower index; its omega_ub caps both.  ~ok_s ⇒ no valid host exists
-    # anywhere (the top-M would have surfaced one), so the shortlist result
-    # (host 0, ok=False) already matches the full path.
-    #
-    # With integer-valued costs (the paper regime; all sums are exact in f32)
-    # ``cost_lb ≤ best_cost`` holds bitwise and ``u < best_val`` is already
-    # safe.  With arbitrary float costs the bound's ≤K-term sums may overshoot
-    # the enumeration's subset sums by a few ulp of reassociation error, so
-    # pad the strict branch by that margin; the exact-tie branch keeps the
-    # fast path for mass-tied fleets (see module docstring for the residual
-    # ulp-tie caveat on non-integer inputs).
-    if m_term_gate:
-        # python ``abs`` for the static program (constant-folded as before);
-        # jnp.abs when the lane value is a tracer.
-        m_abs = abs(m_term) if mult_val is None else jnp.abs(m_term)
-        tol = m_abs * ispan * (3.0 * k * 1.2e-7) * jnp.maximum(
-            jnp.abs(consts.c_hi), jnp.abs(consts.c_lo)
+    with jax.named_scope("stage2"):
+        ispan = inv_span(consts.c_lo, consts.c_hi)
+        bc_s, bm_s, _ = _plan_terms(use_pallas, gathered=True)(
+            free_f[cand], inst_res[cand], inst_cost[cand], inst_valid[cand],
+            req_res, masks,
         )
-    else:
-        tol = 0.0
-    admissible = (u < best_val - tol) | ((u == best_val) & (j_u > w_star)) | ~ok_s
-    margin = jnp.where(ok_s, best_val - u, jnp.float32(POS_INF))
+        bc_s = jnp.where(req_preemptible, 0.0, bc_s)
+        bm_s = jnp.where(req_preemptible, 0, bm_s)
+        omega_s = omega_of(bc_s, base_c, valid_c, consts, ispan, m_term,
+                           gate=m_term_gate)  # (M,)
+        best_val = jnp.max(omega_s)
+        # Winner = lowest ORIGINAL index among exact-score ties (what the
+        # full path's argmax-first-hit does over the whole fleet).
+        tie_idx = jnp.where(omega_s == best_val, cand, n_hosts)
+        winner_pos = jnp.argmin(tie_idx).astype(jnp.int32)
+        w_star = tie_idx[winner_pos].astype(jnp.int32)
+        ok_s = best_val > NEG_INF / 2
+
+        # ---- admissibility: can any non-shortlisted host still win? ---------
+        # An outside host beats w* only with omega > best_val, or omega ==
+        # best_val and a lower index; its omega_ub caps both.  ~ok_s ⇒ no
+        # valid host exists anywhere (the top-M would have surfaced one), so
+        # the shortlist result (host 0, ok=False) already matches the full
+        # path.
+        #
+        # With integer-valued costs (the paper regime; all sums are exact in
+        # f32) ``cost_lb ≤ best_cost`` holds bitwise and ``u < best_val`` is
+        # already safe.  With arbitrary float costs the bound's ≤K-term sums
+        # may overshoot the enumeration's subset sums by a few ulp of
+        # reassociation error, so pad the strict branch by that margin; the
+        # exact-tie branch keeps the fast path for mass-tied fleets (see
+        # module docstring for the residual ulp-tie caveat on non-integer
+        # inputs).
+        if m_term_gate:
+            # python ``abs`` for the static program (constant-folded as
+            # before); jnp.abs when the lane value is a tracer.
+            m_abs = abs(m_term) if mult_val is None else jnp.abs(m_term)
+            tol = m_abs * ispan * (3.0 * k * 1.2e-7) * jnp.maximum(
+                jnp.abs(consts.c_hi), jnp.abs(consts.c_lo)
+            )
+        else:
+            tol = 0.0
+        admissible = (
+            (u < best_val - tol) | ((u == best_val) & (j_u > w_star)) | ~ok_s
+        )
+        margin = jnp.where(ok_s, best_val - u, jnp.float32(POS_INF))
+
+    @jax.named_scope("fallback")
+    def fallback(_):
+        return full_decision(None)
 
     h, bm, ok = jax.lax.cond(
         admissible,
         lambda _: (w_star, bm_s[winner_pos], ok_s),
-        full_decision,
+        fallback,
         operand=None,
     )
     return h, bm, ok, ~admissible, margin
@@ -1262,6 +1274,7 @@ def build_fleet_state(
 # mirror and the simulators do exactly that).
 
 
+@jax.named_scope("transition")
 def _apply_decision(
     state: SoAFleetState,
     host_idx: jax.Array,      # () int32

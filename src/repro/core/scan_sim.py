@@ -488,7 +488,7 @@ class _ScanCarry:
                                           # degradation) preemptible flag
     ev_wait: Optional[jax.Array] = None   # (E+1,) f32 sim-time queue wait
                                           # at placement (-1 = never placed)
-    adm: Optional[jax.Array] = None       # (7,) i32 admission counters
+    adm: Optional[jax.Array] = None       # (9,) i32 admission counters
     next_deadline: Optional[jax.Array] = None  # () f32 earliest enq + SLO
 
     def tree_flatten(self):
@@ -503,10 +503,10 @@ _C_PLACED_N, _C_PLACED_P, _C_FAILED_N, _C_FAILED_P = 0, 1, 2, 3
 _C_PREEMPT, _C_STORMS, _C_STORM_KILLS = 4, 5, 6
 
 _A_ARRIVALS, _A_ADMITTED, _A_REJ_OVER, _A_REJ_RETRY = 0, 1, 2, 3
-_A_DRAINS, _A_RETRIES, _A_DEGRADED = 4, 5, 6
+_A_DRAINS, _A_RETRIES, _A_DEGRADED, _A_ATTEMPTS, _A_FALLBACKS = 4, 5, 6, 7, 8
 _ADM_NAMES = (
     "arrivals", "admitted", "rejected_overflow", "rejected_retry", "drains",
-    "retries", "degraded",
+    "retries", "degraded", "attempts", "fallbacks",
 )
 
 _COL_ORDER = tuple(f.name for f in dataclasses.fields(EventTrace))
@@ -553,6 +553,7 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
 
     no_y = (jnp.int32(-1), jnp.int32(-1), jnp.asarray(False), jnp.int32(0))
 
+    @jax.named_scope("arrival")
     def ev_arrival(c, ev):
         e, t, r, p, pr, ckk, pd, pc, dm, zn, fr, tg, hs = ev
         if streaming:
@@ -562,9 +563,10 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
                 pr >= 0, pr,
                 jnp.where(p, jnp.int32(policy.n_classes - 1), jnp.int32(0)),
             )
-            q, slot, okp = queue_push(
-                c.qstate, r, p, dm, ckk, pd, jnp.int32(-1), klass, t, pc,
-            )
+            with jax.named_scope("queue_push"):
+                q, slot, okp = queue_push(
+                    c.qstate, r, p, dm, ckk, pd, jnp.int32(-1), klass, t, pc,
+                )
             adm = c.adm.at[_A_ARRIVALS].add(1)
             adm = adm.at[_A_REJ_OVER].add((~okp).astype(jnp.int32))
             counters = c.counters
@@ -620,6 +622,7 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
              jnp.where(placed_pre, s, -1).astype(jnp.int32), ok, n_kill)
         return c, y
 
+    @jax.named_scope("departure")
     def ev_departure(c, ev):
         e, t, r, p, pr, ckk, pd, pc, dm, zn, fr, tg, hs = ev
         tgc = jnp.clip(tg, 0, e_total)
@@ -643,6 +646,7 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
         )
         return c, no_y
 
+    @jax.named_scope("fail_host")
     def ev_fail(c, ev):
         e, t, r, p, pr, ckk, pd, pc, dm, zn, fr, tg, hs = ev
         h = jnp.clip(hs, 0, n - 1)
@@ -656,6 +660,7 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
         )
         return c, no_y
 
+    @jax.named_scope("heal_host")
     def ev_heal(c, ev):
         e, t, r, p, pr, ckk, pd, pc, dm, zn, fr, tg, hs = ev
         h = jnp.clip(hs, 0, n - 1)
@@ -663,6 +668,7 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
             c, state=set_schedulable(c.state, h, jnp.asarray(True))
         ), no_y
 
+    @jax.named_scope("checkpoint")
     def ev_checkpoint(c, ev):
         e, t, r, p, pr, ckk, pd, pc, dm, zn, fr, tg, hs = ev
         tgc = jnp.clip(tg, 0, e_total)
@@ -677,6 +683,7 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
         )
         return dataclasses.replace(c, state=st), no_y
 
+    @jax.named_scope("zone_storm")
     def ev_storm(c, ev):
         e, t, r, p, pr, ckk, pd, pc, dm, zn, fr, tg, hs = ev
         st = c.state
@@ -717,6 +724,7 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
     branches = (ev_arrival, ev_departure, ev_fail, ev_heal, ev_checkpoint,
                 ev_storm, ev_pad)
 
+    @jax.named_scope("drain")
     def drain(c, now):
         """One in-carry admission drain: select → ``_step_core`` scan → pop.
 
@@ -726,19 +734,20 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
         into the carry arrays instead of python lists.
         """
         q = c.qstate
-        idx, take = queue_select(
-            q, policy.admit_batch, now=now, aging_rate=aging,
-            n_classes=policy.n_classes,
-        )
-        b = idx.shape[0]
-        b_res = jnp.where(take[:, None], q.res[idx], PAD_RES)
-        b_pre = jnp.where(take, q.preemptible[idx], False)
-        b_dom = jnp.where(take, q.domain[idx], -1)
-        b_kind = jnp.where(take, q.cost_kind[idx], -1)
-        b_period = jnp.where(take, q.period[idx], -1.0)
-        b_price = jnp.where(take, q.price[idx], 1.0)
-        b_now = jnp.full((b,), now, jnp.float32)
-        src = jnp.where(take, c.q_src[idx], e_total).astype(jnp.int32)
+        with jax.named_scope("queue_select"):
+            idx, take = queue_select(
+                q, policy.admit_batch, now=now, aging_rate=aging,
+                n_classes=policy.n_classes,
+            )
+            b = idx.shape[0]
+            b_res = jnp.where(take[:, None], q.res[idx], PAD_RES)
+            b_pre = jnp.where(take, q.preemptible[idx], False)
+            b_dom = jnp.where(take, q.domain[idx], -1)
+            b_kind = jnp.where(take, q.cost_kind[idx], -1)
+            b_period = jnp.where(take, q.period[idx], -1.0)
+            b_price = jnp.where(take, q.price[idx], 1.0)
+            b_now = jnp.full((b,), now, jnp.float32)
+            src = jnp.where(take, c.q_src[idx], e_total).astype(jnp.int32)
 
         orig_pre = b_pre
         if storm_thr is None:
@@ -753,7 +762,7 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
 
         def attempt(cc, xs):
             src_e, r, p, dm, t_, pc_, kd_, pd_ = xs
-            st, (h, s, ok, kill, _fb, _mg) = _step_core(
+            st, (h, s, ok, kill, fb, _mg) = _step_core(
                 cc.state, r, p, dm, t_, pc_, kd_, pd_, policy,
                 req_exclude=None, mult_val=mult_val,
             )
@@ -793,18 +802,20 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
                 normal_res=cc.normal_res.at[h].add(r0),
                 counters=counters,
             )
-            return cc, ok
+            return cc, (ok, fb)
 
-        c, ok_b = lax.scan(
-            attempt, c,
-            (src, b_res, b_pre, b_dom, b_now, b_price, b_kind, b_period),
-        )
-        placed = ok_b & take
-        wait = jnp.where(placed, now - q.enq_t[idx], 0.0)
-        ev_wait = c.ev_wait.at[src].set(
-            jnp.where(placed, wait, c.ev_wait[src])
-        )
-        q2, dropped = queue_pop(q, idx, take, placed, policy.max_retries)
+        with jax.named_scope("decide"):
+            c, (ok_b, fb_b) = lax.scan(
+                attempt, c,
+                (src, b_res, b_pre, b_dom, b_now, b_price, b_kind, b_period),
+            )
+        with jax.named_scope("queue_pop"):
+            placed = ok_b & take
+            wait = jnp.where(placed, now - q.enq_t[idx], 0.0)
+            ev_wait = c.ev_wait.at[src].set(
+                jnp.where(placed, wait, c.ev_wait[src])
+            )
+            q2, dropped = queue_pop(q, idx, take, placed, policy.max_retries)
         # Rejections (retries exhausted) book as failures under the ORIGINAL
         # preemptible flag — the queue stores it; demotion is per-attempt.
         counters = c.counters
@@ -822,6 +833,10 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
         )
         adm = adm.at[_A_DEGRADED].add(jnp.sum(degraded.astype(jnp.int32)))
         adm = adm.at[_A_DRAINS].add(1)
+        adm = adm.at[_A_ATTEMPTS].add(jnp.sum(take.astype(jnp.int32)))
+        adm = adm.at[_A_FALLBACKS].add(
+            jnp.sum((fb_b & take).astype(jnp.int32))
+        )
         nd = jnp.min(
             jnp.where(q2.valid, q2.enq_t, jnp.float32(jnp.inf))
         ) + slo
@@ -879,7 +894,7 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
             ev_kill=jnp.zeros((s1,), jnp.int32),
             ev_pre=jnp.zeros((s1,), bool),
             ev_wait=jnp.full((s1,), -1.0, jnp.float32),
-            adm=jnp.zeros((7,), jnp.int32),
+            adm=jnp.zeros((len(_ADM_NAMES),), jnp.int32),
             next_deadline=jnp.float32(jnp.inf),
         )
     xs = (kind, jnp.arange(e_total, dtype=jnp.int32), time, res, pre, prio,
@@ -902,7 +917,8 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
                 lambda c2: c2, cc,
             )
 
-        carry = lax.fori_loop(0, limit, _epilogue, carry)
+        with jax.named_scope("epilogue"):
+            carry = lax.fori_loop(0, limit, _epilogue, carry)
         # Per-arrival outcomes resolve at drain boundaries, not event rows —
         # read them off the final carry instead of the scan's ys.
         ys = (carry.ev_host[:e_total], carry.ev_slot[:e_total],
@@ -974,7 +990,8 @@ class ScanResult:
     #: final wait-queue arrays (streaming mode only; numpy-materialized)
     queue: Optional[AdmissionQueueState] = None
     #: admission counters: arrivals / admitted / rejected_overflow /
-    #: rejected_retry / drains / retries / degraded / queue_depth
+    #: rejected_retry / drains / retries / degraded / attempts / fallbacks
+    #: / queue_depth
     admission: Optional[Dict[str, int]] = None
     #: (E,) f32 sim-time enqueue→absorb wait per arrival row (-1 = never
     #: placed: rejected, or a non-arrival row)

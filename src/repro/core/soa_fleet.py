@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import obs
 from .admission import AdmissionFrontEnd, DrainResult, PAD_RES
 from .cost import CostFunction
 from .jax_scheduler import (
@@ -646,22 +647,23 @@ class SoAFleet:
         zone's churn denominator (a voluntary exit is evidence the zone is
         *healthy*: uptime without a termination).  Without ``now`` the zone
         accumulators are untouched — the exact pre-churn transition."""
-        inst = self.instances.pop(instance_id, None)
-        if inst is None:
-            return False
-        host_idx, slot = self.locator.pop(instance_id)
-        if slot is not None:
-            mask = np.zeros((self.k_slots,), bool)
-            mask[slot] = True
-            self.state = apply_termination(
-                self.state, host_idx, mask, now=now, involuntary=False
-            )
-            self.slot_ids[host_idx][slot] = None
-        else:
-            self.state = apply_departure(
-                self.state, host_idx, inst.resources.vec32
-            )
-        return True
+        with obs.span(obs.DEPART):
+            inst = self.instances.pop(instance_id, None)
+            if inst is None:
+                return False
+            host_idx, slot = self.locator.pop(instance_id)
+            if slot is not None:
+                mask = np.zeros((self.k_slots,), bool)
+                mask[slot] = True
+                self.state = apply_termination(
+                    self.state, host_idx, mask, now=now, involuntary=False
+                )
+                self.slot_ids[host_idx][slot] = None
+            else:
+                self.state = apply_departure(
+                    self.state, host_idx, inst.resources.vec32
+                )
+            return True
 
     def preempt_instance(
         self, instance_id: str, now: Optional[float] = None

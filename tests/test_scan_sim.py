@@ -533,7 +533,7 @@ STREAM_MIXED_POLICY = SchedulerPolicy(
 )
 
 _ADM_KEYS = ("arrivals", "admitted", "rejected_overflow", "rejected_retry",
-             "drains", "retries", "degraded")
+             "drains", "retries", "degraded", "attempts", "fallbacks")
 
 
 def _assert_stream_equal(py_sim: SoASimulator, dev: ss.ScanResult) -> None:
@@ -550,6 +550,10 @@ def _assert_stream_equal(py_sim: SoASimulator, dev: ss.ScanResult) -> None:
     assert adm["arrivals"] == (
         adm["admitted"] + adm["rejected_overflow"] + adm["rejected_retry"]
         + adm["queue_depth"]
+    )
+    # every attempt is exactly one outcome
+    assert adm["attempts"] == (
+        adm["admitted"] + adm["retries"] + adm["rejected_retry"]
     )
     # final queue arrays, every column bitwise
     for f in dataclasses.fields(front.qstate):
