@@ -56,14 +56,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import obs
-from .jax_scheduler import SoAFleetState, _step_core
+from .jax_scheduler import SoAFleetState, _scan_live_rows, _step_core
 from .policy import COST_KIND_IDS, SchedulerPolicy
-from .screen_math import churn_stats
+from .screen_math import POS_INF, churn_stats
 from .types import Request
 
-#: Padding sentinel for untaken drain rows: a request no host can fit, so
-#: the scan body no-ops it (``ok=False``).  Same value as
-#: ``soa_fleet._PAD_RES`` (which re-exports this one).
+#: Padding sentinel: a request no host can fit, so ``_step_core`` no-ops it
+#: (``ok=False``).  A drain's untaken rows carry it too, though its loop
+#: never runs them.  Same value as ``soa_fleet._PAD_RES`` (which
+#: re-exports this one).
 PAD_RES = 1e30
 
 #: Sort key for invalid queue entries — larger than any real class or seq,
@@ -282,7 +283,10 @@ def _drain_entry(
     Decisions run through the exact ``schedule_many`` scan body at a common
     ``now`` (the drain time), so a drained queue is bit-exact against
     feeding the same requests to the unqueued pipeline in drain order.
-    Untaken rows carry the ``PAD_RES`` sentinel and no-op.
+    Only the taken rows run: they are a prefix of the batch, and the
+    ``decide`` loop stops after them (``_scan_live_rows``).  A row it skips
+    reads ``host_idx`` and ``slot`` -1, ``ok``, ``kill`` and ``fell_back``
+    False, and ``margin`` ``POS_INF`` (a decision with no candidate).
 
     Graceful degradation (``policy.storm_threshold``): when the fleet-wide
     observed churn rate ΣT/max(ΣU, eps) — read off the state's zone
@@ -341,10 +345,14 @@ def _drain_entry(
 
     with jax.named_scope("decide"):
         fleet_state, (host_idx, slot, ok, kill, fell_back, margin) = (
-            jax.lax.scan(
+            _scan_live_rows(
                 body, fleet_state,
                 (b_res, b_pre, b_dom, b_now, b_price, b_kind, b_period,
                  excl_xs),
+                take,
+                (jnp.int32(-1), jnp.int32(-1), jnp.bool_(False),
+                 jnp.zeros((fleet_state.k_slots,), bool), jnp.bool_(False),
+                 jnp.float32(POS_INF)),
             )
         )
     with jax.named_scope("queue_pop"):

@@ -1411,6 +1411,38 @@ def _many_entry(state, req_res, req_preemptible, req_domain, req_now,
     )
 
 
+def _scan_live_rows(body, carry, xs, take, dead):
+    """``lax.scan(body, carry, xs)`` over the taken rows only.
+
+    A drain's ``take`` (B,) is a prefix of its batch (``queue_select``
+    sorts invalid entries last), so the loop runs rows ``[0, sum(take))``
+    and never the padding after them.  A row it skips leaves the carry
+    untouched and reads ``dead`` in every output (a pytree of one row's
+    outputs, broadcast over the B rows).  Under ``vmap`` the loop runs to
+    the largest live count among the lanes, never past B; a lane that is
+    done keeps its carry.
+    """
+    b = jax.tree_util.tree_leaves(xs)[0].shape[0]
+    ys = jax.tree_util.tree_map(
+        lambda d: jnp.broadcast_to(d, (b,) + jnp.shape(d)), dead
+    )
+
+    def row(i, acc):
+        c, ys = acc
+        x = jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), xs
+        )
+        c, y = body(c, x)
+        ys = jax.tree_util.tree_map(
+            lambda buf, v: jax.lax.dynamic_update_index_in_dim(buf, v, i, 0),
+            ys, y,
+        )
+        return c, ys
+
+    n_live = jnp.sum(take.astype(jnp.int32))
+    return jax.lax.fori_loop(0, n_live, row, (carry, ys))
+
+
 _step_donated = functools.partial(
     jax.jit, static_argnames=_STEP_STATICS, donate_argnums=(0,)
 )(_step_entry)

@@ -71,6 +71,7 @@ from .admission import (
 )
 from .jax_scheduler import (
     SoAFleetState,
+    _scan_live_rows,
     _step_core,
     apply_departure,
     apply_host_failure,
@@ -726,12 +727,15 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
 
     @jax.named_scope("drain")
     def drain(c, now):
-        """One in-carry admission drain: select → ``_step_core`` scan → pop.
+        """One in-carry admission drain: select → ``_step_core`` loop → pop.
 
         The pure-transition mirror of ``admission._drain_entry`` (minus the
         push scan — arrivals were already pushed at their event rows), with
         the host mirror's bookkeeping (``AdmissionFrontEnd.flush``) folded
-        into the carry arrays instead of python lists.
+        into the carry arrays instead of python lists.  Like it, the loop
+        runs the taken rows only (``_scan_live_rows``); a skipped row
+        leaves the carry as it was and reads ``ok`` and ``fell_back``
+        False, which the bookkeeping masks by ``take`` in any case.
         """
         q = c.qstate
         with jax.named_scope("queue_select"):
@@ -805,9 +809,10 @@ def _scan_impl(state, cols, normal_res0, sample_every, mult, knobs, policy,
             return cc, (ok, fb)
 
         with jax.named_scope("decide"):
-            c, (ok_b, fb_b) = lax.scan(
+            c, (ok_b, fb_b) = _scan_live_rows(
                 attempt, c,
                 (src, b_res, b_pre, b_dom, b_now, b_price, b_kind, b_period),
+                take, (jnp.bool_(False), jnp.bool_(False)),
             )
         with jax.named_scope("queue_pop"):
             placed = ok_b & take

@@ -21,6 +21,8 @@ exact and equality can be strict (same regime as tests/test_soa_incremental).
 """
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -588,6 +590,48 @@ def test_queue_select_packed_key_matches_lexsort(seed):
                 f"packed-key order diverged from lexsort "
                 f"(n_classes={n_classes}, batch={batch}, aging={aging})"
             )
+
+
+@pytest.mark.parametrize("aging", [0.0, 0.05])
+def test_queue_select_take_is_live_prefix(aging):
+    """The drains run rows ``[0, sum(take))`` only, so ``take`` must be a
+    prefix of the batch holding ``min(depth, B)`` rows — at every depth
+    0..Q, with valid rows scattered over the slots, mixed classes, retried
+    entries, and aging off and on."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(int(aging * 1000) + 14)
+    cap, batch, d, n_classes = 24, 8, 3, 3
+    for depth in range(cap + 1):
+        q = queue_init(cap, d)
+        t = 0.0
+        for _ in range(cap):  # fill, then empty random slots down to depth
+            t += float(rng.integers(0, 30))
+            q, _, ok = queue_push(
+                q, np.ones((d,), np.float32), False, -1, -1, -1.0, -1,
+                int(rng.integers(n_classes)), t, 1.0,
+            )
+            assert bool(ok)
+        gone = rng.choice(cap, size=cap - depth, replace=False)
+        stay = np.setdiff1d(np.arange(cap), gone)
+        valid = np.zeros((cap,), bool)
+        valid[stay] = True
+        tries = np.where(valid, rng.integers(0, 3, size=cap), 0)
+        q = dataclasses.replace(
+            q, valid=jnp.asarray(valid), tries=jnp.asarray(tries, jnp.int32)
+        )
+        assert int(q.depth) == depth
+        idx, take = queue_select(
+            q, batch, now=jnp.float32(t + 100.0), aging_rate=aging,
+            n_classes=n_classes,
+        )
+        idx, take = np.asarray(idx), np.asarray(take)
+        n_live = min(depth, batch)
+        assert int(take.sum()) == n_live, f"depth {depth}"
+        assert np.array_equal(take, np.arange(batch) < n_live), (
+            f"take is not a prefix at depth {depth}: {take}"
+        )
+        assert valid[idx[:n_live]].all()
 
 
 def test_wait_percentile_readers_agree():
