@@ -60,7 +60,8 @@ def _fixed_rule(config, seed: int):
     """``per_node`` instances of one flavour on every node, each preemptible
     with probability ``preemptible_fraction`` (at most ``k_max`` per node;
     a node left with none gets its first made preemptible), started a seeded
-    whole number of minutes in ``age_minutes`` before T0."""
+    whole number of minutes in ``age_minutes`` before T0.  Draws node by
+    node; ``fixed_bulk`` is the same law drawn in bulk."""
     p = config["prefill"]
     size = np.asarray(config["flavors"][p["flavor"]], np.float64)
     lo, hi = p["age_minutes"]
@@ -71,10 +72,31 @@ def _fixed_rule(config, seed: int):
         for _ in range(p["per_node"]):
             pre = bool(rng.random() < p["preemptible_fraction"]) and n_pre < p["k_max"]
             n_pre += int(pre)
-            rows.append([h, pre, T0 - float(rng.integers(lo, hi)) * 60.0, size])
+            rows.append([h, pre, T0 - float(rng.integers(lo, hi)) * 60.0])
         if n_pre == 0:
             rows[first][1] = True
-    return rows
+    host = np.array([r[0] for r in rows], np.int64)
+    return (host, np.array([r[1] for r in rows], bool),
+            np.array([r[2] for r in rows], np.float64),
+            np.tile(size, (host.size, 1)))
+
+
+def _fixed_bulk_rule(config, seed: int):
+    """The fixed rule's law (``_fixed_rule``) with every draw made in one
+    array call, for fleets of millions of instances: the ``per_node``
+    preemptible draws and ages of node ``h`` are row ``h`` of two
+    (hosts, per_node) draws."""
+    p = config["prefill"]
+    size = np.asarray(config["flavors"][p["flavor"]], np.float64)
+    lo, hi = p["age_minutes"]
+    n, c = config["hosts"], p["per_node"]
+    rng = np.random.default_rng(seed)
+    pre = rng.random((n, c)) < p["preemptible_fraction"]
+    age = rng.integers(lo, hi, size=(n, c)).astype(np.float64) * 60.0
+    pre &= np.cumsum(pre, axis=1) <= p["k_max"]
+    pre[:, 0] |= ~pre.any(axis=1)
+    return (np.repeat(np.arange(n, dtype=np.int64), c), pre.reshape(-1),
+            (T0 - age).reshape(-1), np.tile(size, (n * c, 1)))
 
 
 def _fill_rule(config, seed: int):
@@ -103,11 +125,13 @@ def _fill_rule(config, seed: int):
         take[:, c] = active
         used += size * active[:, None]
     hs, cs = np.nonzero(take)          # host-major, in fill order
-    return [[int(h), bool(pre[h, c]), T0 - age[h, c], sizes[flav[h, c]]]
-            for h, c in zip(hs, cs)]
+    return (hs.astype(np.int64), pre[hs, cs], T0 - age[hs, cs],
+            sizes[flav[hs, cs]])
 
 
-_RULES = {"fixed": _fixed_rule, "fill": _fill_rule}
+#: a rule returns its instances host-major, in fill order, as arrays:
+#: (host, preemptible, start, sizes)
+_RULES = {"fixed": _fixed_rule, "fixed_bulk": _fixed_bulk_rule, "fill": _fill_rule}
 
 
 def prefill(config, seed: int) -> Fleet:
@@ -115,28 +139,23 @@ def prefill(config, seed: int) -> Fleet:
     the first instance of a seeded ``free_fraction`` of the nodes), with its
     hosts in the order ``seed`` gives: every seed holds the same hosts."""
     p = config["prefill"]
-    rows = _RULES[p["rule"]](config, BASE_SEED)
+    host, pre, start, res = _RULES[p["rule"]](config, BASE_SEED)
     n = config["hosts"]
     rng = np.random.default_rng(BASE_SEED + 1)
     drop = rng.random(n) < p["free_fraction"]
-    keep, seen = [], set()
-    for i, row in enumerate(rows):
-        h = row[0]
-        if drop[h] and h not in seen:
-            seen.add(h)
-            continue
-        keep.append(i)
-    rows = [rows[i] for i in keep]
+    first = np.zeros(host.size, bool)
+    first[np.unique(host, return_index=True)[1]] = True
+    keep = np.flatnonzero(~(drop[host] & first))
     place = np.random.default_rng(np.random.SeedSequence([seed, 11])).permutation(n)
     return Fleet(
         n=n,
         cap=np.asarray(config["node"], np.float64),
         k_slots=int(config["k_slots"]),
-        ids=[f"x{keep[j]}" for j in range(len(rows))],
-        host=place[np.array([r[0] for r in rows], np.int64)],
-        res=np.array([r[3] for r in rows], np.float64).reshape(-1, len(config["node"])),
-        pre=np.array([r[1] for r in rows], bool),
-        start=np.array([r[2] for r in rows], np.float64),
+        ids=[f"x{i}" for i in keep.tolist()],
+        host=place[host[keep]],
+        res=res[keep].reshape(-1, len(config["node"])),
+        pre=pre[keep],
+        start=start[keep],
     )
 
 
@@ -209,4 +228,40 @@ def arrivals(config, rate: float, count: int, seed: int) -> Arrivals:
     return Arrivals(
         t=t, flavor=flavor.astype(np.int64), res=sizes[flavor], pre=pre,
         life=np.maximum(1.0, np.round(draw)),
+    )
+
+
+@dataclasses.dataclass
+class Jobs:
+    """Jobs in the order they are sent, one ``schedule_batch`` call each:
+    job ``k`` is the requests ``arr[start[k]:start[k + 1]]``, all asking
+    at time ``t[k]``."""
+
+    arr: Arrivals
+    start: np.ndarray    # (J + 1,) int64 offsets into ``arr``
+    t: np.ndarray        # (J,) float64, whole seconds, nondecreasing
+
+    def __len__(self) -> int:
+        return int(self.t.shape[0])
+
+
+def batch_jobs(config, traffic, rate: float, count: int, seed: int) -> Jobs:
+    """At least ``count`` requests of the configuration's mix, cut in
+    stream order into consecutive jobs whose sizes are the traffic's
+    ``job_sizes``, taken in turn; a job's members all ask at the arrival
+    time of its first.  The request stream (``arrivals`` at ``rate``) is
+    drawn from ``BASE_SEED``; ``seed`` orders the requests within each job,
+    so every seed sends the same jobs at the same times, their members in
+    another order."""
+    cycle = np.asarray(traffic["job_sizes"], np.int64)
+    sizes = np.resize(cycle, -(-count // int(cycle.sum())) * cycle.size)
+    base = arrivals(config, rate, int(sizes.sum()), BASE_SEED)
+    start = np.r_[0, np.cumsum(sizes)]
+    mix = np.random.default_rng(np.random.SeedSequence([seed, 13]))
+    req = np.concatenate([start[k] + mix.permutation(sizes[k])
+                          for k in range(sizes.size)])
+    return Jobs(
+        arr=Arrivals(t=base.t[start[:-1]].repeat(sizes), flavor=base.flavor[req],
+                     res=base.res[req], pre=base.pre[req], life=base.life[req]),
+        start=start, t=base.t[start[:-1]],
     )

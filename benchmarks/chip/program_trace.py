@@ -62,6 +62,10 @@ class ProgramTrace:
     spans: List[Tuple[float, float, str]]  # sched.* host spans
     t_lo: float                 # first device event
     window_s: float
+    #: each device plane's leaf operations: (``op_name`` path, HLO
+    #: category, seconds), one entry per operation
+    plane_leaves: Dict[str, List[Tuple[Tuple[str, ...], str, float]]] = \
+        dataclasses.field(default_factory=dict)
 
     @classmethod
     def of(cls, ops: Iterable[Op], spans: Iterable[Tuple[float, float, str]],
@@ -72,6 +76,21 @@ class ProgramTrace:
             if op.leaf:
                 acc.leaf(op.dur, op.path)
         return acc.result(list(spans), window_s)
+
+    @property
+    def chips(self) -> int:
+        """Device planes that ran a leaf operation (at least 1)."""
+        return max(1, len(self.plane_leaves))
+
+    def chip_leaf_s(self, need: Sequence[str],
+                    skip: Sequence[str] = ()) -> List[float]:
+        """Seconds of the leaf operations on each device plane (sorted by
+        plane) whose ``op_name`` path holds every scope in ``need``,
+        leaving out those whose HLO category holds one of ``skip``."""
+        return [sum(d for path, cat, d in self.plane_leaves[p]
+                    if all(x in path for x in need)
+                    and not any(x in cat for x in skip))
+                for p in sorted(self.plane_leaves)]
 
     def idle_by_span(self) -> Dict[str, float]:
         """Idle device seconds from the first device event over the
@@ -107,23 +126,26 @@ class _Acc:
     def __init__(self):
         self.intervals: List[Tuple[float, float]] = []
         self.scope_s: Dict[str, float] = collections.defaultdict(float)
+        self.plane_leaves: Dict[str, list] = collections.defaultdict(list)
         self.leaf_s = self.unscoped_s = 0.0
 
     def interval(self, start: float, dur: float) -> None:
         self.intervals.append((start, start + dur))
 
-    def leaf(self, dur: float, path: Sequence[str]) -> None:
+    def leaf(self, dur: float, path: Tuple[str, ...], plane: str = "",
+             category: str = "") -> None:
         self.leaf_s += dur
         if not path:
             self.unscoped_s += dur
         for name in set(path).intersection(SCOPES):
             self.scope_s[name] += dur
+        self.plane_leaves[plane].append((path, category, dur))
 
     def result(self, spans, window_s: float) -> ProgramTrace:
         t_lo = min((a for a, _ in self.intervals), default=0.0)
         return ProgramTrace(dict(self.scope_s), self.leaf_s, self.unscoped_s,
                             tracing.union(self.intervals), spans, t_lo,
-                            window_s)
+                            window_s, dict(self.plane_leaves))
 
 
 # ---------------------------------------------------------------------------
@@ -233,10 +255,12 @@ def _device_plane(plane, names: Dict[int, str], acc: _Acc) -> None:
     for mid, d in dur.items():
         st = {names.get(x.metadata_id): _stat_str(x, names)
               for x in plane.event_metadata[mid].stats}
-        if st.get("hlo_category") in CONTAINERS:
+        cat = st.get("hlo_category", "")
+        if cat in CONTAINERS:
             continue
         tf_op = st.get("tf_op", "")
-        acc.leaf(d, tuple(tf_op.rsplit(":", 1)[0].split("/")) if tf_op else ())
+        acc.leaf(d, tuple(tf_op.rsplit(":", 1)[0].split("/")) if tf_op else (),
+                 plane.name, cat)
 
 
 def newest(trace_dir: str) -> str:
@@ -259,12 +283,13 @@ def program(ctx) -> Optional[ProgramTrace]:
     if ctx.summary is None:
         return None
     if getattr(ctx, "program", None) is None:
-        pt = ctx.program = read(newest(TRACE_DIR))
-        n = traced_drains(ctx)
-        per = ", ".join(f"{k} {1e3 * pt.scope_s.get(k, 0.0) / n:.3f}"
+        pt = ctx.program = read(newest(getattr(ctx, "trace_dir", TRACE_DIR)))
+        n, unit = per_unit(ctx)
+        per = ", ".join(f"{k} {1e3 * pt.scope_s.get(k, 0.0) / pt.chips / n:.3f}"
                         for k in SCOPES if k in pt.scope_s) if n else "-"
         ctx.note.append(
-            f"program scopes, device ms per drain over {n} drains: {per}; "
+            f"program scopes, device ms per {unit} over {n} {unit}s, mean of "
+            f"{pt.chips} chip(s): {per}; "
             f"leaf ops {1e3 * pt.leaf_s:.3f} ms, of which unscoped "
             f"{1e3 * pt.unscoped_s:.3f} ms")
         if pt.busy:
@@ -298,6 +323,15 @@ def traced_drains(ctx) -> int:
     return _admission(ctx).get("drains", 0) * (ctx.traced_events // n_real)
 
 
+def per_unit(ctx) -> Tuple[int, str]:
+    """What a device time is read per: the traced drains, or the traced
+    scan rows where the cell has no drains (the batch cell)."""
+    n = traced_drains(ctx)
+    if not n and getattr(ctx, "traced_rows", 0):
+        return ctx.traced_rows, "scan row"
+    return n, "drain"
+
+
 def drain_fill(ctx):
     st = _stats(ctx)
     if st is not None:
@@ -323,11 +357,13 @@ def scan_fallback_share(ctx):
 
 
 def stage2_device_ms(ctx):
+    """Device ms under the ``stage2`` scope per drain, or per scan row
+    where the cell has no drains, averaged over the chips."""
     pt = program(ctx)
     if pt is None or "stage2" not in pt.scope_s:
         return None
-    n = traced_drains(ctx)
-    return 1e3 * pt.scope_s["stage2"] / n if n else None
+    n = per_unit(ctx)[0]
+    return 1e3 * pt.scope_s["stage2"] / pt.chips / n if n else None
 
 
 def idle_program_ms(ctx):

@@ -76,3 +76,88 @@ def device_idle(ctx):
 
 def compiles_in_window(ctx):
     return None if ctx.compiles is None else float(ctx.compiles)
+
+
+# ---------------------------------------------------------------------------
+# the batch cell (``schedule_many`` over a fleet sharded across the chips):
+# device times are the mean over the chips, with their spread in a note
+# ---------------------------------------------------------------------------
+
+#: ``schedule_many``'s device module
+MANY_MODULE = ("_many_entry",)
+#: operations (names and HLO categories) that exchange data between chips
+COLLECTIVES = ("all-gather", "all-reduce", "collective-permute", "all-to-all",
+               "reduce-scatter")
+#: the batch harness's host spans outside the call into the program
+BATCH_SPANS = ("bench.pack", "bench.absorb", "bench.depart")
+
+
+def _chip_note(ctx, what: str, patterns) -> float:
+    """Mean device seconds over the chips of ``patterns``, noting each
+    chip's and the spread between them."""
+    per = ctx.summary.per_chip(patterns)
+    mean = sum(per) / len(per) if per else 0.0
+    if per and mean > 0:
+        ctx.note.append(
+            f"{what} per chip (s): {', '.join(f'{t:.6f}' for t in per)}; "
+            f"spread (max - min) / mean {100.0 * (max(per) - min(per)) / mean:.3f}%")
+    return mean
+
+
+def many_device_us(ctx):
+    if ctx.summary is None or not ctx.traced_decisions:
+        return None
+    t = _chip_note(ctx, "schedule_many module", MANY_MODULE)
+    if t <= 0:
+        return None
+    ctx.note.append(f"many_device_us base: {ctx.traced_decisions} decisions in "
+                    f"{ctx.traced_rows} scan rows")
+    return 1e6 * t / ctx.traced_decisions
+
+
+def sharded_screen_roofline(ctx):
+    """The stage-1 screen of one shard against ``roofline.screen_counts`` of
+    the hosts a chip holds (``ctx.shape``), once per scan row: its time is
+    the leaf operations under the program's ``stage1`` scope inside its
+    ``shard_map`` (the kernel or the XLA screen, whichever runs), less the
+    collectives, averaged over the chips."""
+    import program_trace
+
+    pt = program_trace.program(ctx)
+    if pt is None or not ctx.traced_rows:
+        return None
+    per = pt.chip_leaf_s(("stage1", "shard_map"), COLLECTIVES)
+    t = sum(per) / len(per) if per else 0.0
+    if t <= 0:
+        return None
+    ctx.note.append("stage-1 screen in each shard per chip (s): " + ", ".join(
+        f"{x:.6f}" for x in per) + f"; spread (max - min) / mean "
+        f"{100.0 * (max(per) - min(per)) / t:.3f}%")
+    ops, nbytes = roofline.screen_counts(*ctx.shape)
+    share, bound = roofline.roofline_share(ops * ctx.traced_rows,
+                                           nbytes * ctx.traced_rows, t,
+                                           ctx.device_kind)
+    ctx.note.append(f"screen_roofline: {ctx.traced_rows} screens a chip, "
+                    f"{t * 1e6 / ctx.traced_rows:.3f} us each, {nbytes:.0f} B "
+                    f"and {ops:.0f} ops each, {bound}-bound")
+    return share
+
+
+def collective_us(ctx):
+    if ctx.summary is None or not ctx.traced_decisions:
+        return None
+    if not ctx.summary.op_time(COLLECTIVES)[1]:
+        return None
+    t = _chip_note(ctx, "collectives", COLLECTIVES)
+    return 1e6 * t / ctx.traced_decisions
+
+
+def batch_host_ms(ctx):
+    """Host ms per decision: the harness's spans around a job (packing its
+    requests, absorbing its outcomes, departures) and the device-idle time
+    inside its call into the program (the program's own packing, dispatch,
+    fetch and absorption)."""
+    if ctx.summary is None or not ctx.traced_decisions:
+        return None
+    inside = dict(ctx.summary.idle_gaps()).get("bench.schedule", 0.0)
+    return 1e3 * (ctx.summary.span_time(BATCH_SPANS) + inside) / ctx.traced_decisions

@@ -69,7 +69,14 @@ class Decision:
 
 
 class RefFleet:
-    """The fleet's state as the reference keeps it."""
+    """The fleet's state as the reference keeps it.
+
+    Every quantity a decision reads per host (its rounded view of the
+    state, its fit tests, its best-subset cost at one time) is a function
+    of that host's row alone.  Such a quantity is kept between decisions
+    and recomputed for the hosts that a transition changed since
+    (``_changed``), so a decision over a million hosts reads a few cached
+    arrays instead of recomputing them."""
 
     def __init__(self, fleet, period: float):
         n, k = fleet.n, fleet.k_slots
@@ -85,23 +92,32 @@ class RefFleet:
         self.where: Dict[str, Tuple[int, int, bool, np.ndarray]] = {}
         self.zone_term = np.float32(0.0)
         self.zone_up = np.float32(0.0)
-        #: per-host change counter, and the best-subset costs of every host
-        #: for one (time, request, precision), valid while versions match
-        self.version = np.zeros(n, np.int64)
-        self._costs: Dict[tuple, Tuple[np.ndarray, np.ndarray]] = {}
+        #: hosts changed by each transition, in order; a cache keeps how
+        #: far into it its rows are current
+        self._changed: List[int] = []
+        self._cache: Dict[tuple, Tuple[np.ndarray, int]] = {}
+        # every instance counts on the full view, normal ones on both;
         # preemptible instances fill slots in id order on each host
-        order = sorted(range(len(fleet.ids)), key=lambda i: fleet.ids[i])
-        nxt = np.zeros(n, np.int64)
-        for i in order:
-            h = int(fleet.host[i])
-            self.free_f[h] -= fleet.res[i]
-            if fleet.pre[i]:
-                s = int(nxt[h])
-                nxt[h] += 1
-                self._set_slot(h, s, fleet.ids[i], fleet.res[i], fleet.start[i])
-            else:
-                self.free_n[h] -= fleet.res[i]
-                self.where[fleet.ids[i]] = (h, -1, False, fleet.res[i])
+        host = np.asarray(fleet.host, np.int64)
+        pre = np.asarray(fleet.pre, bool)
+        np.subtract.at(self.free_f, host, fleet.res)
+        np.subtract.at(self.free_n, host[~pre], fleet.res[~pre])
+        order = np.argsort(np.asarray(fleet.ids), kind="stable")
+        order = order[pre[order]]
+        order = order[np.argsort(host[order], kind="stable")]
+        hp = host[order]
+        group = np.r_[0, np.flatnonzero(hp[1:] != hp[:-1]) + 1]
+        slot = np.arange(hp.size) - np.repeat(group, np.diff(np.r_[group, hp.size]))
+        self.valid[hp, slot] = True
+        self.res[hp, slot] = fleet.res[order]
+        self.start[hp, slot] = fleet.start[order]
+        slots = np.full(host.size, -1, np.int64)
+        slots[order] = slot
+        ids = fleet.ids
+        for i, h, s in zip(order.tolist(), hp.tolist(), slot.tolist()):
+            self.slot_id[h][s] = ids[i]
+        self.where = dict(zip(ids, zip(host.tolist(), slots.tolist(),
+                                       pre.tolist(), fleet.res)))
 
     def _set_slot(self, h, s, iid, res, start):
         self.valid[h, s] = True
@@ -110,14 +126,31 @@ class RefFleet:
         self.slot_id[h][s] = iid
         self.where[iid] = (h, s, True, res)
 
+    def _rows(self, key: tuple, fn) -> np.ndarray:
+        """``fn(hosts)`` for every host, computed once and kept under
+        ``key``; the rows of hosts changed since are recomputed."""
+        got = self._cache.get(key)
+        if got is None:
+            val = fn(np.arange(self.free_f.shape[0]))
+        else:
+            val, seen = got
+            if seen < len(self._changed):
+                hs = np.unique(np.asarray(self._changed[seen:], np.int64))
+                val[hs] = fn(hs)
+        self._cache[key] = (val, len(self._changed))
+        return val
+
     # -- the decision ----------------------------------------------------------
     def _view(self, precision: str):
         """The state's numbers as the decision reads them: as kept, or every
         one of them rounded to bfloat16 (the control)."""
         if precision == "float32":
             return self.free_f, self.free_n, self.res, self.start, _exact
-        return (_bf16(self.free_f), _bf16(self.free_n), _bf16(self.res),
-                _bf16(self.start), _bf16)
+        return (self._rows(("bf16", "free_f"), lambda hs: _bf16(self.free_f[hs])),
+                self._rows(("bf16", "free_n"), lambda hs: _bf16(self.free_n[hs])),
+                self._rows(("bf16", "res"), lambda hs: _bf16(self.res[hs])),
+                self._rows(("bf16", "start"), lambda hs: _bf16(self.start[hs])),
+                _bf16)
 
     def best_subset(self, h: int, req, now: float, precision: str = "float32"):
         """Cheapest feasible victim subset on host ``h`` as (cost, slots),
@@ -142,20 +175,26 @@ class RefFleet:
                precision: str = "float32") -> Decision:
         free_f, free_n, res, _, rnd = self._view(precision)
         req = rnd(np.asarray(req, np.float64))
+        key = (precision, tuple(req))
         if pre:
-            fit = np.all(free_f >= req - EPS, axis=1) & ~self.valid.all(axis=1)
-            hs = np.flatnonzero(fit)
-            if not hs.size:
+            fit = self._rows(("pre",) + key, lambda hs: np.all(
+                free_f[hs] >= req - EPS, axis=1) & ~self.valid[hs].all(axis=1))
+            h = int(np.argmax(fit))
+            if not fit[h]:
                 return Decision(False)
-            h = int(hs[0])
             return Decision(True, h, int(np.argmin(self.valid[h])))
-        cand = np.all(free_n >= req - EPS, axis=1)
-        pool = rnd(free_f + rnd((res * self.valid[..., None]).sum(axis=1)))
-        cand &= np.all(pool >= req - EPS, axis=1)
-        direct = cand & np.all(free_f >= req - EPS, axis=1)
-        hs = np.flatnonzero(direct)
-        if hs.size:
-            return Decision(True, int(hs[0]))
+
+        def cand_of(hs):
+            pool = rnd(free_f[hs] + rnd((res[hs] * self.valid[hs][..., None]).sum(axis=1)))
+            return (np.all(free_n[hs] >= req - EPS, axis=1)
+                    & np.all(pool >= req - EPS, axis=1))
+
+        cand = self._rows(("cand",) + key, cand_of)
+        direct = self._rows(("direct",) + key, lambda hs: cand[hs] & np.all(
+            free_f[hs] >= req - EPS, axis=1))
+        h = int(np.argmax(direct))
+        if direct[h]:
+            return Decision(True, h)
         hs = np.flatnonzero(cand)
         if not hs.size:
             return Decision(False)
@@ -167,20 +206,12 @@ class RefFleet:
 
     def _all_costs(self, req: np.ndarray, now: float, precision: str) -> np.ndarray:
         """Best feasible subset cost of every host (inf where none), kept
-        between decisions at one time and recomputed for changed hosts."""
-        key = (now, tuple(req), precision)
-        if key not in self._costs:
-            if any(k[0] != now for k in self._costs):
-                self._costs.clear()
-            hs = np.arange(self.free_f.shape[0])
-            self._costs[key] = (self._host_costs(hs, req, now, precision),
-                                self.version.copy())
-        cost, seen = self._costs[key]
-        changed = np.flatnonzero(self.version != seen)
-        if changed.size:
-            cost[changed] = self._host_costs(changed, req, now, precision)
-            seen[changed] = self.version[changed]
-        return cost
+        between decisions at one time."""
+        key = ("cost", now, tuple(req), precision)
+        if key not in self._cache:
+            for k in [k for k in self._cache if k[0] == "cost" and k[1] != now]:
+                del self._cache[k]
+        return self._rows(key, lambda hs: self._host_costs(hs, req, now, precision))
 
     def _host_costs(self, hs: np.ndarray, req: np.ndarray, now: float,
                     precision: str):
@@ -212,7 +243,7 @@ class RefFleet:
         if not dec.ok:
             return
         h = dec.host
-        self.version[h] += 1
+        self._changed.append(h)
         if dec.victims:
             lost = np.float32(0.0)
             for s in dec.victims:
@@ -236,7 +267,7 @@ class RefFleet:
         if w is None:
             return False
         h, s, pre, res = w
-        self.version[h] += 1
+        self._changed.append(h)
         self.free_f[h] += res
         if pre:
             self.zone_up = np.float32(self.zone_up + np.float32(now - self.start[h, s]))

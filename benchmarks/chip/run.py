@@ -15,7 +15,10 @@ Inputs are made from ``--seed``.  The run refuses a machine whose JAX
 backend is not a TPU, or has fewer chips than the cell asks for: non-zero
 exit and no result line.  ``--rehearse`` (never passed by a check) runs a
 tiny fleet on the CPU with the stage-1 kernel in interpret mode, to try
-the harness without a chip.
+the harness without a chip; ``--chips`` (never passed by a check either)
+runs a cell on another number of chips than it asks for, as the one-chip
+comparison of a four-chip cell does.  A cell on more than one chip shards
+the fleet host-major over a mesh of its chips.
 
 After the window the program's answers are replayed through the plain
 reference (``reference.py``); each number compared is printed with its
@@ -99,6 +102,7 @@ def import_program(jax):
     """The system under test: the ``repro`` package of this checkout."""
     sys.path.insert(0, os.path.join(ROOT, "src"))
     from repro.core import types as rtypes
+    from repro.core.fleet_sharding import fleet_mesh
     from repro.core.policy import SchedulerPolicy
     from repro.core.scan_sim import EventTrace, simulate_scan
     from repro.core.soa_fleet import SoAFleet
@@ -106,17 +110,20 @@ def import_program(jax):
     return types.SimpleNamespace(
         jax=jax, types=rtypes, SchedulerPolicy=SchedulerPolicy,
         SoAFleet=SoAFleet, EventTrace=EventTrace, simulate_scan=simulate_scan,
-        TraceAnnotation=jax.profiler.TraceAnnotation,
+        TraceAnnotation=jax.profiler.TraceAnnotation, fleet_mesh=fleet_mesh,
     )
 
 
-def policy_of(repro, config, rehearse: bool):
+def policy_of(repro, config, rehearse: bool, chips: int = 1):
+    """The configuration's policy; a cell on more than one chip shards the
+    fleet host-major over a mesh of its chips."""
     p = config["policy"]
+    mesh = {} if chips == 1 else {"mesh": repro.fleet_mesh(chips)}
     return repro.SchedulerPolicy(
         queue_capacity=p["queue_capacity"], admit_batch=p["admit_batch"],
         slo_target_s=p["slo_target_s"], max_retries=p["max_retries"],
         cost_kind=p["cost_kind"], period=p["period_s"],
-        fused_screen=True if rehearse else None,
+        fused_screen=True if rehearse else None, **mesh,
     )
 
 
@@ -242,7 +249,7 @@ def run_served(ctx, repro, args, config, traffic, rehearse):
     mode = traffic["mode"]
     fleet = fleetgen.prefill(config, args.seed)
     sim_rate = fleetgen.little_rate(config, fleet)
-    policy = policy_of(repro, config, rehearse)
+    policy = policy_of(repro, config, rehearse, ctx.chips)
     pol = config["policy"]
     if mode == "open":
         r_wall = REHEARSE_WALL_RATE if rehearse else traffic["wall_rate_per_s"]
@@ -340,7 +347,7 @@ def run_whatif(ctx, repro, args, config, traffic, rehearse):
     fleet = fleetgen.prefill(config, args.seed)
     span = config["whatif_span_s"]
     sim_rate = fleetgen.little_rate(config, fleet)
-    policy = policy_of(repro, config, rehearse)
+    policy = policy_of(repro, config, rehearse, ctx.chips)
     trace = whatif.make_trace(config, sim_rate, span, args.seed)
     state0 = whatif.start_state(repro, fleet, policy)
     et = whatif.event_trace(repro, trace)
@@ -389,7 +396,66 @@ def run_whatif(ctx, repro, args, config, traffic, rehearse):
     return ctx
 
 
-MODES = {"open": run_served, "backlog": run_served, "whatif": run_whatif}
+def run_batch(ctx, repro, args, config, traffic, rehearse):
+    import batch
+
+    fleet = fleetgen.prefill(config, args.seed)
+    rate = fleetgen.little_rate(config, fleet)
+    policy = policy_of(repro, config, rehearse, ctx.chips)
+    count = int(traffic["max_decisions_per_s"] * args.seconds)
+    jobs = fleetgen.batch_jobs(config, traffic, rate, count, args.seed)
+    b = batch.Batch(repro, fleet, jobs, policy, trace=ctx.prof.on)
+    batch.warm_up(b, fleet, traffic["job_sizes"])
+    t_warm = time.perf_counter() - T_START
+    ctx.watch.start()
+    ctx.setup_s = time.perf_counter() - T_START
+    ctx.compiles_counter.armed = True
+    ctx.prof.start()
+    decided, elapsed, ran_out = batch.run_window(b, args.seconds, tick=ctx.prof.tick)
+    ctx.prof.stop()
+    ctx.note.append(ctx.watch.report())
+    ctx.compiles_counter.armed = False
+    ctx.compiles = ctx.compiles_counter.count
+    ctx.read_memory()
+
+    # the program's state to the host (without its padding rows) and freed
+    stt = b.fleet.state
+    state = {f: np.asarray(getattr(stt, f))[:fleet.n] for f in (
+        "free_f", "free_n", "inst_valid", "inst_res", "inst_start")}
+    state.update({f: np.asarray(getattr(stt, f)) for f in ("zone_term", "zone_up")})
+    ctx.shortlist = dict(b.fleet.shortlist_stats)
+    ctx.shape = (stt.n_hosts // ctx.chips, fleet.k_slots, fleet.cap.shape[0],
+                 ctx.shortlist["shortlist"])
+    del stt
+    b.fleet = None
+
+    t_w1 = ctx.prof.wall[1]
+    ctx.traced_decisions = sum(n for at, n, _ in b.done if at <= t_w1)
+    ctx.traced_rows = sum(r for at, _, r in b.done if at <= t_w1)
+    ctx.attempted = decided
+    ctx.failed = b.lost + int(ran_out)
+    ctx.e2e = {"decisions_per_s": decided / elapsed}
+    rows = sum(r for _, _, r in b.done)
+    placed = sum(o[0] for op in b.ops if op[0] == "job" for o in op[2])
+    ctx.note.append(
+        f"batch: {b.sent} jobs, {decided} decisions ({placed} placed, "
+        f"{decided - placed} refused) in {elapsed:.3f} s over {ctx.chips} "
+        f"chip(s); {rows} scan rows, {100.0 * (1 - decided / max(1, rows)):.2f}% "
+        f"padding; simulated {jobs.t[0]:.0f}-{jobs.t[max(0, b.sent - 1)]:.0f} s; "
+        f"{b.departed} departures; set-up to the end of warm-up {t_warm:.3f} s")
+    if ran_out:
+        ctx.note.append(f"the traffic's {len(jobs)} jobs ran out inside the window")
+    if b.lost:
+        ctx.note.append(f"{b.lost} requests came back with no outcome")
+    ctx.batch, ctx.state, ctx.fleet = b, state, fleet
+    t = time.perf_counter()
+    ctx.checks = batch.check(b, state, fleet, config)
+    ctx.note.append(f"reference replay took {time.perf_counter() - t:.3f} s")
+    return ctx
+
+
+MODES = {"open": run_served, "backlog": run_served, "whatif": run_whatif,
+         "batch": run_batch}
 
 
 def execute(argv=None):
@@ -401,6 +467,7 @@ def execute(argv=None):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true", help=argparse.SUPPRESS)
+    ap.add_argument("--chips", type=int, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
     spec = load_json(ROOT, "BENCHMARK.json")
@@ -409,7 +476,8 @@ def execute(argv=None):
     traffic = load_json(BENCH, "traffic", f"{cell['traffic']}.json")
     if args.rehearse:
         config = dict(config, hosts=REHEARSE_HOSTS)
-    started = start_jax(args.rehearse, cell["chips"])
+    chips = cell["chips"] if args.chips is None else args.chips
+    started = start_jax(args.rehearse, chips)
     if started is None:
         return None
     jax, devices = started
@@ -419,15 +487,16 @@ def execute(argv=None):
     ctx = types.SimpleNamespace(
         note=[], raised=False, summary=None, traced_decisions=0,
         traced_events=0, shortlist={}, compiles=None,
-        device_kind=dev.device_kind, memory_peak=0,
+        device_kind=dev.device_kind, memory_peak=0, chips=chips,
     )
     ctx.compiles_counter = CompileCounter(jax)
     ctx.watch = HostWatch()
     ctx.prof = Profiler(jax, bool(args.trace), traffic.get("trace_s", args.seconds))
 
     def read_memory():
-        stats = dev.memory_stats() or {}
-        ctx.memory_peak = int(stats.get("peak_bytes_in_use", 0))
+        """Peak bytes on the fullest chip of the cell's."""
+        ctx.memory_peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                              for d in devices[:chips])
 
     ctx.read_memory = read_memory
     ctx.config = config
@@ -460,6 +529,7 @@ def main(argv=None) -> int:
 
         t0, t1 = ctx.prof.wall
         ctx.summary = tracing.summarize(TRACE_DIR, t1 - t0)
+        ctx.trace_dir = TRACE_DIR
         for m in spec["per_layer"]:
             if args.workload not in m.get("workloads", [args.workload]):
                 continue

@@ -41,15 +41,16 @@ def program_fleet(repro, fleet: fleetgen.Fleet, policy):
     spec = types.VM_SPEC
     cap = types.Resources(spec, fleet.cap)
     hosts = [types.Host(name=f"h{i}", capacity=cap) for i in range(fleet.n)]
-    sizes: Dict[tuple, object] = {}
-    for j, iid in enumerate(fleet.ids):
-        key = tuple(fleet.res[j])
-        if key not in sizes:
-            sizes[key] = types.Resources(spec, fleet.res[j])
-        h = hosts[int(fleet.host[j])]
-        h.instances[iid] = types.Instance(
-            id=iid, resources=sizes[key], preemptible=bool(fleet.pre[j]),
-            host=h.name, start_time=float(fleet.start[j]),
+    uniq, which = np.unique(fleet.res, axis=0, return_inverse=True)
+    sizes = [types.Resources(spec, r) for r in uniq]
+    make = types.Instance
+    for iid, h, pre, start, size in zip(
+            fleet.ids, fleet.host.tolist(), fleet.pre.tolist(),
+            fleet.start.tolist(), which.reshape(-1).tolist()):
+        host = hosts[h]
+        host.instances[iid] = make(
+            id=iid, resources=sizes[size], preemptible=pre, host=host.name,
+            start_time=start,
         )
     return repro.SoAFleet(hosts, k_slots=fleet.k_slots, policy=policy)
 

@@ -88,6 +88,8 @@ class Summary:
         self._busy: Dict[str, _Busy] = defaultdict(_Busy)
         self.ops: Dict[str, List[float]] = defaultdict(lambda: [0.0, 0])
         self.modules: Dict[str, List[float]] = defaultdict(lambda: [0.0, 0])
+        #: device seconds of each operation and module name, by plane
+        self.plane_s: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
         self.spans: List[Event] = []
         self.t_lo, self.t_hi = float("inf"), float("-inf")
 
@@ -103,6 +105,7 @@ class Summary:
                 return
             acc[0] += e.dur
             acc[1] += 1
+            self.plane_s[e.plane][e.name] += e.dur
         elif e.plane.startswith("/host:") and e.name.startswith(SPAN_PREFIX):
             self.spans.append(e)
 
@@ -134,14 +137,26 @@ class Summary:
     def op_time(self, patterns: Sequence[str]) -> Tuple[float, int]:
         return self._sum(self.ops, patterns)
 
+    @property
+    def chips(self) -> int:
+        """Device planes that ran an operation (at least 1)."""
+        return max(1, len(self._busy))
+
+    def per_chip(self, patterns: Sequence[str]) -> List[float]:
+        """Device seconds of the operations and modules whose name holds
+        one of ``patterns``, on each device plane (sorted by plane)."""
+        return [sum(t for name, t in self.plane_s[p].items()
+                    if any(x in name for x in patterns))
+                for p in sorted(self.plane_s)]
+
     def span_time(self, names: Sequence[str]) -> float:
         return sum(e.dur for e in self.spans if e.name in names)
 
     def top_ops(self, n: int = 10) -> List[Tuple[str, float]]:
-        """Device operations that took most time, summed by name (a
-        ``while`` operation's time holds that of the operations in its
-        body)."""
-        return sorted(((k, v[0]) for k, v in self.ops.items()),
+        """Device operations that took most time, summed by name and
+        averaged over the device planes (a ``while`` operation's time
+        holds that of the operations in its body)."""
+        return sorted(((k, v[0] / self.chips) for k, v in self.ops.items()),
                       key=lambda kv: -kv[1])[:n]
 
     def idle_gaps(self, window: Optional[Tuple[float, float]] = None
